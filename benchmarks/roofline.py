@@ -4,7 +4,9 @@ per-(arch × shape) three-term roofline table for the single-pod mesh.
 The artifacts come from ``python -m repro.launch.dryrun``. ``setup`` (called
 by ``benchmarks/run.py`` before timing) generates one cell when none exist —
 in a subprocess, because the dryrun module must own jax initialization
-(``XLA_FLAGS`` host-device count is locked at first import). A run with no
+(``XLA_FLAGS`` host-device count is locked at first import). The child runs
+with ``JAX_PLATFORMS=cpu``: its 512 devices are host devices, and the parent
+may hold the chip. A run with no
 artifacts is a FAILURE, not an empty table: the old behavior of silently
 emitting ``n_evals: 0`` hid a completely broken pipeline (dryrun did not
 even import against this container's jax before the setup-hook fix).
@@ -32,6 +34,7 @@ def setup(fast: bool = True, out_dir: str = "results/dryrun") -> None:
     print(f"[roofline] no dry-run artifacts in {out_dir} — generating "
           f"{arch}/{shape} (takes a few minutes)", flush=True)
     proc = subprocess.run(cmd, timeout=_SETUP_TIMEOUT_S,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
                           capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(

@@ -6,6 +6,8 @@ from any layer of the stack.
 from __future__ import annotations
 
 import dataclasses
+import os
+from pathlib import Path
 from typing import Any, Callable
 
 import jax
@@ -21,6 +23,22 @@ _DTYPES = {
     "int8": jnp.int8,
     "int32": jnp.int32,
 }
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache is ``.jax_cache/`` at the
+    checkout root: a fixed path, so that later runs find what earlier ones
+    compiled. Entry points call this; tests and library code do not.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def dtype_of(name: str | jnp.dtype) -> jnp.dtype:

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _gmm_kernel(x_ref, w_ref, y_ref, acc_scr):
     di = pl.program_id(3)
@@ -76,7 +74,7 @@ def gmm(
         ),
         out_shape=jax.ShapeDtypeStruct((e, c + pc, f + pf), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _pw_kernel(x_ref, w_ref, b_ref, y_ref, acc_scr, *, act: str):
     ki = pl.program_id(2)
@@ -80,7 +78,7 @@ def ibn_pointwise(
         out_specs=pl.BlockSpec((bn, bf), lambda ni, fi, ki: (ni, fi)),
         out_shape=jax.ShapeDtypeStruct((n + pn, cout + pf), x.dtype),
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

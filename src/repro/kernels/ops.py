@@ -13,14 +13,9 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def flash_attention_available() -> bool:
-    return on_tpu()
+    """Whether JAX's default device is a TPU. A backend that fails to start
+    raises here: a broken chip is never taken for a CPU."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def flash_attention(

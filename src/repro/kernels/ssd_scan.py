@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
                 *, q: int):
@@ -36,7 +34,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr,
 
     x = x_ref[0, 0, 0].astype(jnp.float32)      # (Q, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)    # (Q, 1)
-    a = a_ref[0, 0].astype(jnp.float32)         # (1,) scalar per head
+    a = a_ref[pl.program_id(1)]                 # scalar per head (SMEM)
     bm = b_ref[0, 0, 0].astype(jnp.float32)     # (Q, N)
     cm = c_ref[0, 0, 0].astype(jnp.float32)     # (Q, N)
 
@@ -114,7 +112,6 @@ def ssd_scan(
     dtk = dt.transpose(0, 2, 1).reshape(b, h, nc, q, 1)
     bk = jnp.repeat(B, hg, axis=2).transpose(0, 2, 1, 3).reshape(b, h, nc, q, n)
     ck = jnp.repeat(C, hg, axis=2).transpose(0, 2, 1, 3).reshape(b, h, nc, q, n)
-    a2 = A.reshape(h, 1)
 
     y, st = pl.pallas_call(
         functools.partial(_ssd_kernel, q=q),
@@ -122,7 +119,7 @@ def ssd_scan(
         in_specs=[
             pl.BlockSpec((1, 1, 1, q, p), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, 1), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ci: (hi, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # all of A, per-head scalars
             pl.BlockSpec((1, 1, 1, q, n), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, n), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
         ],
@@ -135,10 +132,10 @@ def ssd_scan(
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(xk, dtk, a2, bk, ck)
+    )(xk, dtk, A.astype(jnp.float32), bk, ck)
     y = y.reshape(b, h, sp, p).transpose(0, 2, 1, 3)[:, :s]
     return y, st

@@ -44,7 +44,7 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.config import SHAPES, RunConfig
 from repro.launch import roofline
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.parallel import ctx as pctx
 from repro.train import steps as steps_lib
 
@@ -66,21 +66,6 @@ def _mem_dict(mem) -> dict:
     return out
 
 
-def _shardings(pc, tree):
-    """P-spec pytree -> whatever this jax's ``jit`` accepts as shardings:
-    raw PartitionSpecs on >= 0.5 (the installed mesh context resolves them),
-    explicit NamedShardings on 0.4.x (which rejects bare specs)."""
-    if hasattr(jax, "set_mesh"):
-        return tree
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return jax.tree.map(
-        lambda s: s if s is None else NamedSharding(pc.mesh, s),
-        tree,
-        is_leaf=lambda x: x is None or isinstance(x, PartitionSpec),
-    )
-
-
 def _lower(run: RunConfig, pc):
     """Build + lower the step for this run. Returns the lowered object."""
     mode = run.shape.mode
@@ -94,8 +79,8 @@ def _lower(run: RunConfig, pc):
         abatch = steps_lib.input_specs(run.model, run.shape)
         jitted = jax.jit(
             step,
-            in_shardings=_shardings(pc, (state_specs, bspecs)),
-            out_shardings=_shardings(pc, (state_specs, None)),
+            in_shardings=(state_specs, bspecs),
+            out_shardings=(state_specs, None),
             donate_argnums=(0,),
         )
         return jitted.lower(astate, abatch)
@@ -104,7 +89,7 @@ def _lower(run: RunConfig, pc):
         aparams = steps_lib.abstract_params(run.model)
         abatch = steps_lib.input_specs(run.model, run.shape)
         return jax.jit(
-            step, in_shardings=_shardings(pc, (pspecs, bspecs))
+            step, in_shardings=(pspecs, bspecs)
         ).lower(aparams, abatch)
     step, pspecs, cspecs, bspecs = steps_lib.make_decode_step(run, pc)
     aparams = steps_lib.abstract_params(run.model)
@@ -112,8 +97,8 @@ def _lower(run: RunConfig, pc):
     abatch = steps_lib.input_specs(run.model, run.shape)
     jitted = jax.jit(
         step,
-        in_shardings=_shardings(pc, (pspecs, cspecs, bspecs["tokens"], P())),
-        out_shardings=_shardings(pc, (None, None, cspecs)),
+        in_shardings=(pspecs, cspecs, bspecs["tokens"], P()),
+        out_shardings=(None, None, cspecs),
         donate_argnums=(1,),
     )
     return jitted.lower(
@@ -128,10 +113,7 @@ def _measure(run: RunConfig, pc, want_mem: bool = False) -> dict:
     t0 = time.monotonic()
     compiled = lowered.compile()
     t_compile = time.monotonic() - t0
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per computation
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+    cost = dict(compiled.cost_analysis() or {})
     coll = roofline.parse_collectives(compiled.as_text())
     out = {
         "flops": float(cost.get("flops", 0.0)),
@@ -297,7 +279,7 @@ def dryrun_cell(
     pc = pctx.from_mesh(mesh, multi_pod=multi_pod, fsdp=run.mesh.fsdp_params,
                         tp=run.mesh.tp)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         real = _measure(run, pc, want_mem=True)
         record = dict(
             base,

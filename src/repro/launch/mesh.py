@@ -2,31 +2,18 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device state
 (the dry-run sets XLA_FLAGS before any jax initialization; smoke tests see the
-single real device).
-
-Version compat: ``jax.sharding.AxisType`` / ``jax.set_mesh`` only exist on
-jax >= 0.5-era sharding APIs. On older jax (the container ships 0.4.37) the
-mesh is built without explicit axis types — every axis is "auto" there anyway
-— and ``mesh_context`` falls back to the legacy ``with mesh:`` context
-manager, so this module imports and works on both.
+single real device). Make a mesh current with ``with jax.set_mesh(mesh):``.
 """
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-
-    _AXIS_TYPES = True
-except ImportError:  # jax < 0.5: no explicit axis types, all axes are auto
-    AxisType = None
-    _AXIS_TYPES = False
+from jax.sharding import AxisType
 
 
-def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if _AXIS_TYPES:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """Mesh over ``jax.devices()`` with every axis auto-sharded (tests, the
+    NAHAS mesh-search's h-space knob, ``launch/train.py --mesh``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,21 +22,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     DP gradient all-reduce crosses the pod (DCN) boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for tests / the NAHAS mesh-search (h-space knob)."""
-    return _mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """The context manager that makes ``mesh`` current for jit tracing:
-    ``jax.set_mesh`` on new jax, the legacy ``with mesh:`` on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def host_device_counts() -> int:
-    return len(jax.devices())
+    return make_mesh(shape, axes)

@@ -260,19 +260,17 @@ def chunked_attention_quantized(
 def attention_core(q, k, v, cfg: ModelConfig, **kw) -> jax.Array:
     impl = cfg.attn_impl
     if impl == "flash_pallas":
-        # The Pallas kernel only lowers for TPU and covers the train/prefill
-        # shapes (no cache masking); decode and CPU dry-runs fall through to
-        # the numerically-equivalent chunked path.
+        # The Pallas kernel covers the train/prefill shapes on a TPU. Decode
+        # and cache-masked calls (kv_len, traced q_offset) and non-TPU runs
+        # take the numerically equivalent chunked path. A kernel that fails
+        # to lower on the chip raises.
+        from repro.kernels import ops as kops
+
         no_cache = kw.get("kv_len") is None and isinstance(
             kw.get("q_offset", 0), int)
-        try:
-            from repro.kernels import ops as kops
-
-            if no_cache and kops.flash_attention_available():
-                return kops.flash_attention(q, k, v,
-                                            causal=kw.get("causal", True))
-        except Exception:
-            pass
+        if no_cache and kops.on_tpu():
+            return kops.flash_attention(q, k, v,
+                                        causal=kw.get("causal", True))
         impl = "chunked"
     if impl == "chunked":
         return chunked_attention(q, k, v, chunk=cfg.attn_chunk,
@@ -404,8 +402,6 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Arr
     if pc is not None and pc.tp and table.shape[1] % pc.model_size == 0:
         from jax.sharding import PartitionSpec as P
 
-        from repro.parallel._compat import shard_map
-
         bt = pc.batch_axes if len(pc.batch_axes) > 1 else pc.batch_axes[0]
         tok_spec = P(bt, None) if tokens.shape[0] % pc.batch_size == 0 else P(None, None)
         out_spec = P(tok_spec[0], None, pc.model_axis)
@@ -413,7 +409,7 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Arr
         def body(tok, tab):
             return tab.astype(cdt)[tok]
 
-        x = shard_map(
+        x = jax.shard_map(
             body,
             mesh=pc.mesh,
             in_specs=(tok_spec, P(None, pc.model_axis)),

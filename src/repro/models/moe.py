@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.common import dtype_of, fold_rng, round_up
-from repro.parallel._compat import shard_map
 from repro.config import ModelConfig
 from repro.models import layers as L
 from repro.models import transformer as T
@@ -168,7 +167,7 @@ def moe_ffn(
             aux = jax.lax.pmean(aux, pc.all_axes)
             return out, aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body,
             mesh=pc.mesh,
             in_specs=(P(bspec, None, None), P(None, None), w_spec, w_spec, wo_spec),
@@ -192,7 +191,7 @@ def moe_ffn(
         aux = jax.lax.pmean(aux, pc.all_axes)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=pc.mesh,
         in_specs=(P(bspec, None, None), P(None, None), w_spec, w_spec, wo_spec),

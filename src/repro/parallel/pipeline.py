@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel._compat import shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable,  # (stage_params, x) -> y  (same shape)
@@ -65,7 +63,7 @@ def pipeline_apply(
         )
         return out
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

@@ -735,33 +735,32 @@ class SearchExecutor:
 
     @contextlib.contextmanager
     def _spawn_env(self):
-        """XLA_FLAGS / trace-dir handoff for spawned workers: set the env
-        vars for the children, restore the parent's values right after
-        ``start()`` — initial spawns and slot respawns take the same path."""
-        parent_tracer = obs_trace.active()
-        saved_flags = os.environ.get("XLA_FLAGS")
-        saved_trace = os.environ.get(obs_trace.TRACE_DIR_ENV)
+        """Environment handoff for spawned workers: set the variables for the
+        children, restore the parent's values right after ``start()`` —
+        initial spawns and slot respawns take the same path. Workers are host
+        programs, so ``JAX_PLATFORMS=cpu`` keeps them off any accelerator the
+        parent process holds."""
+        env = {"JAX_PLATFORMS": "cpu"}
         if self.devices_per_worker:
+            flags = os.environ.get("XLA_FLAGS")
             flag = (
                 f"--xla_force_host_platform_device_count="
                 f"{self.devices_per_worker}"
             )
-            os.environ["XLA_FLAGS"] = f"{saved_flags} {flag}" if saved_flags else flag
+            env["XLA_FLAGS"] = f"{flags} {flag}" if flags else flag
+        parent_tracer = obs_trace.active()
         if parent_tracer is not None:
-            os.environ[obs_trace.TRACE_DIR_ENV] = str(parent_tracer.dir)
+            env[obs_trace.TRACE_DIR_ENV] = str(parent_tracer.dir)
+        saved = {k: os.environ.get(k) for k in env}
+        os.environ.update(env)
         try:
             yield
         finally:
-            if self.devices_per_worker:
-                if saved_flags is None:
-                    os.environ.pop("XLA_FLAGS", None)
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
                 else:
-                    os.environ["XLA_FLAGS"] = saved_flags
-            if parent_tracer is not None:
-                if saved_trace is None:
-                    os.environ.pop(obs_trace.TRACE_DIR_ENV, None)
-                else:
-                    os.environ[obs_trace.TRACE_DIR_ENV] = saved_trace
+                    os.environ[k] = v
 
     @staticmethod
     def _start_slot(pool: _ProcessPool, wid: int) -> None:

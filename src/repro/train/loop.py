@@ -75,6 +75,7 @@ def run_training(
         t0 = time.monotonic()
         batch = batch_at(step)
         state, metrics = step_fn(state, batch)
+        jax.block_until_ready(metrics)  # time the device step, not dispatch
         if loop_cfg.fail_at_step is not None and step == loop_cfg.fail_at_step:
             # flush the state so the failure is recoverable, then die like a
             # preempted node would

@@ -96,3 +96,47 @@ def test_ibn_pointwise(n, ci, co, act):
     y = ibn_pointwise(x, w, b, act=act, block_n=64, block_f=32, block_k=32,
                       interpret=True)
     assert jnp.max(jnp.abs(y - ref.ibn_pointwise_ref(x, w, b, act))) < 1e-4
+
+
+def test_flash_pallas_routes_cache_calls_to_chunked(monkeypatch):
+    """attn_impl="flash_pallas": decode-shaped calls (kv_len, traced
+    q_offset) take the chunked path by condition, even on a TPU; cache-free
+    calls there go to the kernel, and its errors propagate."""
+    from repro.config import ModelConfig
+    from repro.kernels import ops
+    from repro.models import layers as L
+
+    cfg = ModelConfig(attn_impl="flash_pallas", attn_chunk=8)
+    ks = jax.random.split(RNG, 3)
+    q = jax.random.normal(ks[0], (2, 1, 4, 16))
+    k = jax.random.normal(ks[1], (2, 24, 2, 16))
+    v = jax.random.normal(ks[2], (2, 24, 2, 16))
+    kw = dict(causal=True, q_offset=jnp.int32(5), kv_len=jnp.int32(6))
+    want = L.chunked_attention(q, k, v, chunk=8, **kw)
+
+    def kernel(*a, **k_):
+        raise AssertionError("call reached the Pallas kernel")
+
+    monkeypatch.setattr(ops, "flash_attention", kernel)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert jnp.array_equal(L.attention_core(q, k, v, cfg, **kw), want)
+
+    sentinel = jnp.zeros_like(q)
+    monkeypatch.setattr(ops, "flash_attention", lambda *a, **k_: sentinel)
+    assert L.attention_core(q, k, v, cfg, causal=True) is sentinel
+
+    # a kernel that fails on the chip raises; nothing falls back in silence
+    monkeypatch.setattr(ops, "flash_attention", kernel)
+    with pytest.raises(AssertionError, match="Pallas kernel"):
+        L.attention_core(q, k, v, cfg, causal=True)
+
+
+def test_on_tpu_does_not_hide_backend_errors(monkeypatch):
+    from repro.kernels import ops
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(ops.jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="backend failed"):
+        ops.on_tpu()

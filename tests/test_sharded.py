@@ -14,7 +14,7 @@ _SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.config import ModelConfig, RunConfig, ShapeConfig, TrainConfig, MeshConfig
-    from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
     from repro.models import api, moe
     from repro.parallel.ctx import ParallelCtx
     from repro.train.steps import make_train_step
@@ -31,7 +31,7 @@ _SCRIPT = textwrap.dedent("""
     params = api.init(jax.random.PRNGKey(0), cfg)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)}
     ref_logits, ref_aux = api.forward(params, batch, cfg, None)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         ep_logits, ep_aux = jax.jit(
             lambda p, b: api.forward(p, b, cfg, pc))(params, batch)
     assert moe.ep_scheme(cfg, pc) == "ep"
@@ -47,7 +47,7 @@ _SCRIPT = textwrap.dedent("""
     assert moe.ep_scheme(cfg2, pc) == "tpe"
     p2 = api.init(jax.random.PRNGKey(0), cfg2)
     r2, _ = api.forward(p2, batch, cfg2, None)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         s2, _ = jax.jit(lambda p, b: api.forward(p, b, cfg2, pc))(p2, batch)
     err2 = float(jnp.max(jnp.abs(r2 - s2)))
     assert err2 < 2e-3, f"TPE vs ref err {err2}"
@@ -68,16 +68,12 @@ _SCRIPT = textwrap.dedent("""
     opt = make_optimizer(run.train)
     state = {"params": dparams, "opt": opt.init(dparams)}
     _, m_ref = jax.jit(step_ref)(state, tb)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step_sh, sspecs, bspecs = make_train_step(run, pc)
-        # NamedSharding works on every jax; bare PartitionSpecs in jit
-        # shardings need the >= 0.5 set_mesh API
-        from jax.sharding import NamedSharding
-        shard = lambda tree: jax.tree.map(
-            lambda s: NamedSharding(mesh, s), tree,
-            is_leaf=lambda x: isinstance(x, P))
-        jstep = jax.jit(step_sh, in_shardings=(shard(sspecs), shard(bspecs)),
-                        out_shardings=(shard(sspecs), None))
+        # bare PartitionSpecs resolve against the mesh set above, as in
+        # launch/train.py --mesh
+        jstep = jax.jit(step_sh, in_shardings=(sspecs, bspecs),
+                        out_shardings=(sspecs, None))
         new_state, m_sh = jstep(state, tb)
     dl = abs(float(m_ref["loss"]) - float(m_sh["loss"]))
     assert dl < 0.02, f"sharded vs ref loss diff {dl}"
@@ -113,8 +109,6 @@ _SCRIPT = textwrap.dedent("""
 
 
 def test_sharded_suite_subprocess():
-    # runs on old and new jax alike: repro.launch.mesh / repro.parallel._compat
-    # feature-detect AxisType, set_mesh and shard_map
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
