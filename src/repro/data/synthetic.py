@@ -19,6 +19,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from repro.config import ModelConfig, ShapeConfig
+from repro.obs import trace
 
 
 def _rng_for(seed: int, step: int, host: int) -> np.random.Generator:
@@ -45,6 +46,10 @@ class LMStream:
         self._probs = probs
 
     def batch_at(self, step: int) -> dict[str, np.ndarray]:
+        with trace.span("repro.data.batch"):
+            return self._batch(step)
+
+    def _batch(self, step: int) -> dict[str, np.ndarray]:
         g = _rng_for(self.seed, step + 1, self.host)
         b, s = self.batch, self.seq_len
         toks = np.empty((b, s), np.int32)

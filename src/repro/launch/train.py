@@ -4,6 +4,11 @@ device; on a real pod the same entry point drives the full mesh.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --smoke \
       --steps 50
+
+``--trace DIR`` records the loop's ``repro.data.batch`` and
+``repro.train.*`` spans as Chrome-trace JSONL (read with
+``scripts/obs_report.py DIR``); ``--profile DIR`` takes a ``jax.profiler``
+trace of the run with the same spans on the device profile's clock.
 """
 from __future__ import annotations
 
@@ -20,6 +25,8 @@ from repro.config import RunConfig, ShapeConfig, TrainConfig
 from repro.data.synthetic import LMStream
 from repro.launch.mesh import make_mesh
 from repro.models import api
+from repro.obs import trace
+from repro.runtime import cli as runtime_cli
 from repro.train.loop import LoopConfig, run_training
 from repro.train.optim import make_optimizer
 from repro.train.steps import make_train_step
@@ -62,6 +69,12 @@ def main():
     ap.add_argument("--ckpt", type=str, default="/tmp/repro_launch_train")
     ap.add_argument("--mesh", type=str, default=None,
                     help="e.g. 2x4 -> (data=2, model=4); default single device")
+    ap.add_argument("--trace", type=str, default=None, metavar="DIR",
+                    help="record the loop's spans as Chrome-trace JSONL in DIR, "
+                         "plus metrics.json (scripts/obs_report.py DIR)")
+    ap.add_argument("--profile", type=str, default=None, metavar="DIR",
+                    help="write a jax.profiler trace of the run to DIR, with the "
+                         "loop's spans beside the device's ops")
     args = ap.parse_args()
 
     enable_compile_cache()
@@ -84,14 +97,24 @@ def main():
                           for k, v in stream.batch_at(i).items()}
     with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
         step, state = init_train(run, pctx.from_mesh(mesh))
-        res = run_training(
-            step, state, batch_at,
-            LoopConfig(total_steps=args.steps,
-                       ckpt_every=max(args.steps // 2, 10),
-                       ckpt_dir=f"{args.ckpt}_"
-                                f"{configs.ALIASES.get(args.arch, args.arch)}",
-                       log_every=10),
-        )
+        tracer = runtime_cli.start_trace(args)
+        if args.profile:
+            trace.annotate(True)
+            jax.profiler.start_trace(args.profile)
+        try:
+            res = run_training(
+                step, state, batch_at,
+                LoopConfig(total_steps=args.steps,
+                           ckpt_every=max(args.steps // 2, 10),
+                           ckpt_dir=f"{args.ckpt}_"
+                                    f"{configs.ALIASES.get(args.arch, args.arch)}",
+                           log_every=10),
+            )
+        finally:
+            if args.profile:
+                jax.profiler.stop_trace()
+                trace.annotate(False)
+            runtime_cli.finish_trace(args, tracer)
     print(f"done: {res.final_step} steps, last loss "
           f"{res.metrics_history[-1]['loss']:.3f}")
 

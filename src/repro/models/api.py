@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import hybrid, moe, ssm, transformer
+from repro.obs import scopes
 from repro.parallel.ctx import ParallelCtx
 
 _FAMS = {
@@ -68,6 +69,7 @@ def decode_step(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope(scopes.LOSS)
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Mean token CE. logits (B,S,V) any float dtype (reduction in fp32);
     labels (B,S) with -1 = ignore."""

@@ -81,8 +81,7 @@ def forward(params, batch, cfg: ModelConfig, pc=None, *, remat: str = "none"):
             return x + y, None
         x, _ = jax.lax.scan(inner, x, params["tail"],
                             unroll=tail if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     return constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                      batch_dim=0)
 
@@ -149,8 +148,7 @@ def decode_step(params, cache, tokens, cache_index, cfg: ModelConfig, pc=None):
         x, new_tail = jax.lax.scan(inner, x, (params["tail"], cache["tail_ssm"]),
                                    unroll=tail if cfg.unroll_scans else 1)
         new_cache["tail_ssm"] = new_tail
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     return logits, new_cache

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.common import dtype_of
 from repro.config import ModelConfig
+from repro.obs import scopes
 
 # ---------------------------------------------------------------------------
 # Initializers
@@ -391,6 +392,7 @@ def init_embedding(rng, cfg: ModelConfig) -> dict:
     return p
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Array:
     """Token embedding lookup. With a ParallelCtx the gather runs inside an
     explicit shard_map over the model axis (table sharded on d_model): XLA's
@@ -434,3 +436,11 @@ def unembed(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         c = cfg.logit_softcap
         logits = jnp.tanh(logits / c) * c
     return logits
+
+
+@jax.named_scope(scopes.LM_HEAD)
+def lm_head(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The final norm and the unembedding of a whole model's params:
+    hidden states (B, S, D) -> logits (B, S, V)."""
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    return unembed(params["embed"], x, cfg)

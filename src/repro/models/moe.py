@@ -301,8 +301,7 @@ def forward(
     body = T.remat_wrap(body, remat)
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"],
                                unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     return logits, aux / cfg.num_layers
@@ -337,8 +336,7 @@ def decode_step(params, cache, tokens, cache_index, cfg: ModelConfig, pc=None):
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
                                 unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     return logits, new_cache

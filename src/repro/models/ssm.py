@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.common import dtype_of, fold_rng
 from repro.config import ModelConfig
 from repro.models import layers as L
+from repro.obs import scopes
 from repro.parallel.ctx import constrain
 
 # ---------------------------------------------------------------------------
@@ -36,6 +37,7 @@ def segsum(x: jax.Array) -> jax.Array:
     return jnp.where(mask, diff, -jnp.inf)
 
 
+@jax.named_scope(scopes.SSD)
 def ssd_chunked(
     x: jax.Array,  # (B, S, H, P)  already includes nothing; dt applied inside
     dt: jax.Array,  # (B, S, H) fp32 (post-softplus)
@@ -96,6 +98,7 @@ def ssd_chunked(
     return y.astype(x.dtype), final_state
 
 
+@jax.named_scope(scopes.SSD)
 def ssd_decode(x, dt, A, Bm, Cm, state):
     """Single-token recurrence. x: (B,1,H,P), dt: (B,1,H), B/C: (B,1,G,N),
     state: (B,H,P,N) fp32. Returns (y (B,1,H,P), new_state)."""
@@ -176,6 +179,7 @@ def init_ssm_cache(cfg: ModelConfig, batch: int) -> dict:
     }
 
 
+@jax.named_scope(scopes.MIXER)
 def mamba_mixer(
     params: dict,
     x: jax.Array,
@@ -268,8 +272,7 @@ def forward(params, batch, cfg: ModelConfig, pc=None, *, remat: str = "none"):
     body = remat_wrap(body, remat)
     x, _ = jax.lax.scan(body, x, params["layers"],
                         unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     return constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                      batch_dim=0)
 
@@ -293,8 +296,7 @@ def decode_step(params, cache, tokens, cache_index, cfg: ModelConfig, pc=None):
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
                                 unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     return logits, new_cache

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.common import dtype_of, fold_rng
 from repro.config import ModelConfig
 from repro.models import layers as L
+from repro.obs import scopes
 from repro.parallel.ctx import constrain
 from repro.serving import kvcache
 
@@ -59,17 +60,20 @@ def block_apply(
     cache: Optional[dict] = None,
     cache_index=None,
 ) -> tuple[jax.Array, Optional[dict]]:
-    h, new_cache = L.attention_block(
-        params["attn"],
-        L.rmsnorm(params["attn_norm"], x, cfg.norm_eps),
-        cfg,
-        positions=positions,
-        cache=cache,
-        cache_index=cache_index,
-    )
+    with jax.named_scope(scopes.ATTENTION):
+        h, new_cache = L.attention_block(
+            params["attn"],
+            L.rmsnorm(params["attn_norm"], x, cfg.norm_eps),
+            cfg,
+            positions=positions,
+            cache=cache,
+            cache_index=cache_index,
+        )
     x = x + h
-    x = x + L.mlp_block(params["mlp"], L.rmsnorm(params["mlp_norm"], x, cfg.norm_eps), cfg)
-    return x, new_cache
+    with jax.named_scope(scopes.MLP):
+        xn = L.rmsnorm(params["mlp_norm"], x, cfg.norm_eps)
+        h = L.mlp_block(params["mlp"], xn, cfg)
+    return x + h, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -149,8 +153,7 @@ def forward(
     body = remat_wrap(body, remat)
     x, kv = jax.lax.scan(body, x, params["layers"],
                          unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     if return_cache:
@@ -203,8 +206,7 @@ def decode_step(
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
                                 unroll=cfg.num_layers if cfg.unroll_scans else 1)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg)
+    logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,
                        batch_dim=0)
     return logits, new_cache

@@ -1,4 +1,4 @@
-"""Process-local metrics: counters, gauges, log-bucket histograms, one merge.
+"""Process-local metrics: one rate, one merge, a registry of live stats.
 
 The repo's stats surfaces (``EngineStats``, ``StoreStats``, ``CascadeStats``,
 ``ServeStats``) each carry monotone counters plus derived ``*_rate``
@@ -15,19 +15,15 @@ primitives replace all of that:
   recomputed from the SUMMED counters (averaging per-shard rates would
   weight an idle shard the same as a busy one).
 
-:class:`MetricsRegistry` is the process-local registry on top: named
-counters/gauges/histograms for code that wants free-form metrics (the
-tracer, benchmarks), plus weak registration of live stats objects so
-``export()`` can snapshot everything observable in the process without any
-surface pushing updates. Histograms use fixed log-spaced buckets, so p50/p90
-/p99 come from counts alone — no sample storage, O(1) record cost.
+:class:`MetricsRegistry` is the process-local registry on top: weak
+registration of live stats objects, so ``export()`` can snapshot everything
+observable in the process without any surface pushing updates.
 
 Stdlib only; safe to import from any layer.
 """
 
 from __future__ import annotations
 
-import math
 import threading
 import weakref
 from typing import Iterable, Mapping, Optional
@@ -35,9 +31,6 @@ from typing import Iterable, Mapping, Optional
 __all__ = [
     "rate",
     "merge_stats",
-    "Counter",
-    "Gauge",
-    "Histogram",
     "MetricsRegistry",
     "REGISTRY",
 ]
@@ -102,153 +95,23 @@ def merge_stats(
     return total
 
 
-# ---- primitives -----------------------------------------------------------
-
-
-class Counter:
-    """Monotone counter. ``inc`` is unsynchronized by design — CPython's
-    GIL keeps the fast path cheap and per-event races only ever undercount
-    telemetry, never corrupt it."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.value = 0
-
-    def inc(self, n: int = 1) -> None:
-        self.value += n
-
-
-class Gauge:
-    """Last-write-wins instantaneous value."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        self.value = v
-
-
-class Histogram:
-    """Fixed log-spaced buckets: quantiles from counts alone.
-
-    Buckets span ``[10^LO_DECADE, 10^HI_DECADE)`` with ``PER_DECADE``
-    buckets per decade (~16% relative resolution); values outside the span
-    clamp into the edge buckets. ``record`` is two arithmetic ops and an
-    array increment — no sample is ever stored, so a histogram's memory is
-    constant no matter how many values it sees.
-    """
-
-    LO_DECADE = -7  # 100 ns, when recording seconds
-    HI_DECADE = 5
-    PER_DECADE = 16
-    _N = (HI_DECADE - LO_DECADE) * PER_DECADE
-
-    __slots__ = ("name", "counts", "count", "total", "min", "max")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.counts = [0] * self._N
-        self.count = 0
-        self.total = 0.0
-        self.min = math.inf
-        self.max = -math.inf
-
-    def record(self, v: float) -> None:
-        if v != v or v == math.inf:  # NaN/inf would poison the totals
-            return
-        self.count += 1
-        self.total += v
-        if v < self.min:
-            self.min = v
-        if v > self.max:
-            self.max = v
-        if v <= 0.0:
-            self.counts[0] += 1
-            return
-        i = int((math.log10(v) - self.LO_DECADE) * self.PER_DECADE)
-        self.counts[min(max(i, 0), self._N - 1)] += 1
-
-    def _bucket_upper(self, i: int) -> float:
-        return 10.0 ** (self.LO_DECADE + (i + 1) / self.PER_DECADE)
-
-    def quantile(self, q: float) -> float:
-        """Upper edge of the bucket holding the q-quantile (exact min/max
-        for q at the ends)."""
-        if self.count == 0:
-            return 0.0
-        if q <= 0:
-            return self.min
-        if q >= 1:
-            return self.max
-        target = q * self.count
-        seen = 0
-        for i, c in enumerate(self.counts):
-            seen += c
-            if seen >= target:
-                return min(self._bucket_upper(i), self.max)
-        return self.max
-
-    def summary(self) -> dict:
-        if self.count == 0:
-            return {"count": 0}
-        return {
-            "count": self.count,
-            "mean": self.total / self.count,
-            "min": self.min,
-            "p50": self.quantile(0.5),
-            "p90": self.quantile(0.9),
-            "p99": self.quantile(0.99),
-            "max": self.max,
-        }
-
-
 # ---- registry -------------------------------------------------------------
 
 
 class MetricsRegistry:
-    """Process-local registry: named primitives + weakly-held stats objects.
+    """Process-local registry of weakly-held stats objects.
 
     ``register(group, obj)`` holds a weakref to any object with
     ``as_dict()`` (the repo's stats dataclasses self-register on
     construction); transient engines/stores vanish from ``export()`` when
     they are garbage collected, so a long process running thousands of
-    searches never leaks registry entries. ``export()`` snapshots
-    everything: primitives by name, and each stats group folded through
-    :func:`merge_stats`.
+    searches never leaks registry entries. ``export()`` snapshots each
+    stats group folded through :func:`merge_stats`.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._counters: dict[str, Counter] = {}
-        self._gauges: dict[str, Gauge] = {}
-        self._histograms: dict[str, Histogram] = {}
         self._stats: dict[str, list] = {}  # group -> [weakref]
-
-    def counter(self, name: str) -> Counter:
-        with self._lock:
-            c = self._counters.get(name)
-            if c is None:
-                c = self._counters[name] = Counter(name)
-            return c
-
-    def gauge(self, name: str) -> Gauge:
-        with self._lock:
-            g = self._gauges.get(name)
-            if g is None:
-                g = self._gauges[name] = Gauge(name)
-            return g
-
-    def histogram(self, name: str) -> Histogram:
-        with self._lock:
-            h = self._histograms.get(name)
-            if h is None:
-                h = self._histograms[name] = Histogram(name)
-            return h
 
     def register(self, group: str, obj) -> None:
         """Weakly register a live stats object (anything with ``as_dict``)
@@ -266,12 +129,7 @@ class MetricsRegistry:
     def export(self) -> dict:
         """One dict of everything observable in this process right now."""
         with self._lock:
-            out: dict = {
-                "counters": {n: c.value for n, c in self._counters.items()},
-                "gauges": {n: g.value for n, g in self._gauges.items()},
-                "histograms": {n: h.summary() for n, h in self._histograms.items()},
-                "stats": {},
-            }
+            out: dict = {"stats": {}}
             for group, refs in self._stats.items():
                 live = [r() for r in refs]
                 live = [o for o in live if o is not None]
@@ -282,11 +140,8 @@ class MetricsRegistry:
         return out
 
     def reset(self) -> None:
-        """Drop all primitives and registrations (tests/benchmarks)."""
+        """Drop all registrations (tests/benchmarks)."""
         with self._lock:
-            self._counters.clear()
-            self._gauges.clear()
-            self._histograms.clear()
             self._stats.clear()
 
 

@@ -12,8 +12,14 @@ per-file synthetic ``pid`` and a process_name metadata event, so Perfetto /
 ``chrome://tracing`` shows one labeled track per worker with the spawn and
 steady-state phases visible side by side.
 
+**Two sinks, one API.** A span goes to the JSONL tracer (:func:`start`)
+and, while :func:`annotate` is on, into the device profile as a
+``jax.profiler.TraceAnnotation`` of the same name: it then lands on the
+profile's host plane, on the clock of the device's ops. jax is imported on
+the first :func:`annotate` only, so this module stays stdlib-only until then.
+
 **Disabled cost is the design constraint.** :func:`span` reads one module
-global; when no tracer is active it returns a shared no-op whose
+global; when every sink is off it returns a shared no-op whose
 ``__enter__``/``__exit__`` are empty — a few tens of ns per call, cheap
 enough to leave in every hot path permanently. Sub-µs paths (serve
 ``best()``) skip even that via the manual guard::
@@ -32,7 +38,7 @@ whole trace to start at ts=0. Cross-process skew is therefore bounded by
 epoch-clock sampling jitter (µs-scale on one host), not by spawn ordering.
 
 Tracing is observational only: nothing here touches RNG streams, store
-bytes or checkpoint payloads. Stdlib only.
+bytes or checkpoint payloads. Stdlib only, but for jax once annotate() is on.
 """
 
 from __future__ import annotations
@@ -50,6 +56,7 @@ __all__ = [
     "TRACE_DIR_ENV",
     "span",
     "active",
+    "annotate",
     "start",
     "stop",
     "start_from_env",
@@ -194,6 +201,16 @@ class Tracer:
 # ---- module-level switch (THE hot-path guard) -----------------------------
 
 _tracer: Optional[Tracer] = None
+#: jax.profiler.TraceAnnotation while the profiler sink is on
+_annotation = None
+#: what span() reads: None when every sink is off, else (tracer, annotation)
+_sinks: Optional[tuple] = None
+
+
+def _set_sinks() -> None:
+    global _sinks
+    off = _tracer is None and _annotation is None
+    _sinks = None if off else (_tracer, _annotation)
 
 
 def active() -> Optional[Tracer]:
@@ -212,6 +229,7 @@ def start(
     if _tracer is not None:
         stop()
     _tracer = Tracer(path, worker=worker, label=label)
+    _set_sinks()
     return _tracer
 
 
@@ -219,10 +237,25 @@ def stop() -> Optional[Path]:
     """Flush, close and disable the process tracer; returns its path."""
     global _tracer
     tr, _tracer = _tracer, None
+    _set_sinks()
     if tr is None:
         return None
     tr.close()
     return tr.path
+
+
+def annotate(on: bool = True) -> None:
+    """Turn the profiler sink on or off: while on, every :func:`span` also
+    enters ``jax.profiler.TraceAnnotation(name)``, so a profile taken with
+    ``jax.profiler`` shows the span beside the device's ops."""
+    global _annotation
+    if on:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+    _set_sinks()
 
 
 def start_from_env(
@@ -255,19 +288,26 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_args", "_t0")
+    __slots__ = ("_tr", "_ann", "_name", "_args", "_t0")
 
-    def __init__(self, tr: Tracer, name: str, args: dict):
-        self._tr = tr
+    def __init__(self, sinks: tuple, name: str, args: dict):
+        self._tr, ann = sinks
+        self._ann = None if ann is None else ann(name)
         self._name = name
         self._args = args
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tr.now()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tr is not None:
+            self._t0 = self._tr.now()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tr.complete(self._name, self._t0, self._args or None)
+        if self._tr is not None:
+            self._tr.complete(self._name, self._t0, self._args or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
     def set(self, **kw) -> "_Span":
@@ -278,13 +318,14 @@ class _Span:
 
 def span(name: str, **args):
     """``with span("simulate_batch", n=32): ...`` — records a complete
-    event when a tracer is active, else returns the shared no-op. Exceptions
+    event when a tracer is active and a profiler annotation while
+    :func:`annotate` is on, else returns the shared no-op. Exceptions
     propagate; the span still records (the failing interval is usually the
     interesting one)."""
-    tr = _tracer
-    if tr is None:
+    sinks = _sinks
+    if sinks is None:
         return _NOOP
-    return _Span(tr, name, args)
+    return _Span(sinks, name, args)
 
 
 # ---- merge (segments -> one viewable trace) -------------------------------

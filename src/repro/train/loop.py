@@ -22,6 +22,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.obs import trace
 from repro.train import checkpoint as ckpt
 
 
@@ -73,9 +74,12 @@ def run_training(
     pending = None
     for step in range(start, loop_cfg.total_steps):
         t0 = time.monotonic()
-        batch = batch_at(step)
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics)  # time the device step, not dispatch
+        with trace.span("repro.train.batch"):
+            batch = batch_at(step)
+        with trace.span("repro.train.step"):
+            state, metrics = step_fn(state, batch)
+        with trace.span("repro.train.wait"):
+            jax.block_until_ready(metrics)  # time the device step, not dispatch
         if loop_cfg.fail_at_step is not None and step == loop_cfg.fail_at_step:
             # flush the state so the failure is recoverable, then die like a
             # preempted node would
@@ -94,14 +98,16 @@ def run_training(
             history.append(m)
             log_fn(f"[train] {json.dumps(m)}")
         if (step + 1) % loop_cfg.ckpt_every == 0:
-            if pending is not None:
-                pending.join()
-            pending = ckpt.save(
-                loop_cfg.ckpt_dir, step + 1, state, keep=loop_cfg.keep,
-                blocking=not loop_cfg.async_ckpt,
-            )
-    if pending is not None:
-        pending.join()
+            with trace.span("repro.train.checkpoint"):
+                if pending is not None:
+                    pending.join()
+                pending = ckpt.save(
+                    loop_cfg.ckpt_dir, step + 1, state, keep=loop_cfg.keep,
+                    blocking=not loop_cfg.async_ckpt,
+                )
     final = loop_cfg.total_steps
-    ckpt.save(loop_cfg.ckpt_dir, final, state, keep=loop_cfg.keep, blocking=True)
+    with trace.span("repro.train.checkpoint"):
+        if pending is not None:
+            pending.join()
+        ckpt.save(loop_cfg.ckpt_dir, final, state, keep=loop_cfg.keep, blocking=True)
     return LoopResult(final, history, stragglers, resumed_from)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.common import global_norm
 from repro.config import TrainConfig
+from repro.obs import scopes
 
 
 class Optimizer(NamedTuple):
@@ -49,6 +50,18 @@ def _is_matrix(x) -> bool:
 
 
 def make_optimizer(cfg: TrainConfig) -> Optimizer:
+    """The optimizer ``cfg.optimizer`` names; its whole step, clipping
+    included, runs in the ``optimizer`` scope."""
+    opt = _optimizer(cfg)
+
+    def step(params, grads, state):
+        with jax.named_scope(scopes.OPTIMIZER):
+            return opt.step(params, grads, state)
+
+    return Optimizer(opt.init, step)
+
+
+def _optimizer(cfg: TrainConfig) -> Optimizer:
     sched = lr_schedule(cfg)
 
     if cfg.optimizer == "adamw":

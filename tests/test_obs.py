@@ -35,10 +35,13 @@ CFG = SearchConfig(samples=24, batch=8, controller="evolution")
 @pytest.fixture(autouse=True)
 def _tracing_off():
     """Every test starts and ends with tracing disabled — a test that
-    starts a tracer must not leak it into the rest of the suite."""
+    starts a tracer or the profiler sink must not leak it into the rest of
+    the suite."""
     obs_trace.stop()
+    obs_trace.annotate(False)
     yield
     obs_trace.stop()
+    obs_trace.annotate(False)
 
 
 # ---------------------------------------------------------------------------
@@ -94,39 +97,12 @@ def test_merge_counts_bools():
 
 
 # ---------------------------------------------------------------------------
-# primitives + registry
+# registry
 # ---------------------------------------------------------------------------
-
-
-def test_histogram_quantiles_from_buckets_alone():
-    h = obs_metrics.Histogram("t")
-    for v in [1e-3] * 50 + [1e-2] * 40 + [1e-1] * 10:
-        h.record(v)
-    s = h.summary()
-    assert s["count"] == 100
-    assert s["min"] == 1e-3 and s["max"] == 1e-1
-    # quantile = upper bucket edge: within one log bucket (~16%) of truth
-    assert 1e-3 <= s["p50"] <= 1e-3 * 1.2
-    assert 1e-2 <= s["p90"] <= 1e-2 * 1.2
-    assert s["p99"] == pytest.approx(1e-1, rel=0.2)
-    assert s["mean"] == pytest.approx(0.0145)
-
-
-def test_histogram_ignores_nan_inf_clamps_nonpositive():
-    h = obs_metrics.Histogram("t")
-    h.record(float("nan"))
-    h.record(float("inf"))
-    assert h.count == 0
-    h.record(0.0)
-    h.record(-1.0)
-    assert h.count == 2 and h.counts[0] == 2
 
 
 def test_registry_export_and_weak_registration():
     reg = MetricsRegistry()
-    reg.counter("evals").inc(3)
-    reg.gauge("depth").set(2.5)
-    reg.histogram("lat").record(0.01)
 
     @dataclasses.dataclass
     class S:
@@ -140,9 +116,6 @@ def test_registry_export_and_weak_registration():
     reg.register("store", a)
     reg.register("store", b)
     out = reg.export()
-    assert out["counters"]["evals"] == 3
-    assert out["gauges"]["depth"] == 2.5
-    assert out["histograms"]["lat"]["count"] == 1
     assert out["stats"]["store"]["gets"] == 40
     assert out["stats"]["store"]["instances"] == 2
     del b  # dead object drops out of the next export
@@ -200,6 +173,108 @@ def test_span_disabled_is_cheap():
             pass
     per_call_ns = (time.perf_counter_ns() - t0) / n
     assert per_call_ns < 2_000, f"disabled span cost {per_call_ns:.0f}ns/op"
+
+
+def test_span_off_again_after_the_profiler_sink():
+    obs_trace.annotate(True)
+    assert obs_trace.span("x") is not obs_trace._NOOP
+    assert obs_trace.active() is None  # the JSONL tracer stays off
+    obs_trace.annotate(False)
+    assert obs_trace.span("x") is obs_trace._NOOP
+
+
+def test_profiler_sink_puts_spans_in_the_device_profile(tmp_path):
+    """With annotate on, a span is a TraceAnnotation: it shows up on the
+    host plane of a jax.profiler trace, beside the JSONL record."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: x * 2)
+    x = jnp.ones(4)
+    f(x).block_until_ready()
+    obs_trace.start(tmp_path / "jsonl")
+    obs_trace.annotate(True)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with obs_trace.span("repro.test.span", n=1):
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    obs_trace.stop()
+    pb = sorted((tmp_path / "prof").rglob("*.xplane.pb"))[-1]
+    host = [
+        ev.name
+        for plane in ProfileData.from_file(str(pb)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    ]
+    assert "repro.test.span" in host
+    lines = (tmp_path / "jsonl" / "trace.jsonl").read_text().splitlines()
+    assert any(json.loads(ln).get("name") == "repro.test.span" for ln in lines)
+
+
+def test_traced_training_loop_records_its_spans(tmp_path):
+    """A traced run_training writes the data and loop spans to the JSONL
+    file: one of each per step, one per checkpoint."""
+    import jax.numpy as jnp
+
+    from repro.data.synthetic import LMStream
+    from repro.train.loop import LoopConfig, run_training
+
+    stream = LMStream(16, 8, 2, seed=0)
+
+    def step_fn(state, batch):
+        x = state["x"] + jnp.asarray(batch["tokens"]).sum()
+        return {"x": x}, {"loss": x}
+
+    obs_trace.start(tmp_path / "trace")
+    run_training(step_fn, {"x": jnp.zeros(())}, stream.batch_at,
+                 LoopConfig(total_steps=4, ckpt_every=2, ckpt_dir=str(tmp_path / "ck"),
+                            log_every=100, async_ckpt=False),
+                 log_fn=lambda s: None)
+    obs_trace.stop()
+    lines = (tmp_path / "trace" / "trace.jsonl").read_text().splitlines()
+    names = [e["name"] for e in map(json.loads, lines) if e.get("ph") == "X"]
+    for name in ("repro.data.batch", "repro.train.batch", "repro.train.step",
+                 "repro.train.wait"):
+        assert names.count(name) == 4
+    assert names.count("repro.train.checkpoint") == 3  # steps 2 and 4, the final save
+
+
+def test_train_launcher_trace_and_profile(tmp_path, monkeypatch, capsys):
+    """``launch/train.py --trace DIR --profile DIR``: the loop's spans in the
+    JSONL file, and on the host plane of the run's device profile."""
+    import sys
+
+    from jax.profiler import ProfileData
+
+    from repro.launch import train as launch_train
+
+    monkeypatch.setattr(launch_train, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "train", "--arch", "mamba2-370m", "--smoke", "--steps", "2", "--seq", "16",
+        "--batch", "2", "--ckpt", str(tmp_path / "ck"),
+        "--trace", str(tmp_path / "trace"), "--profile", str(tmp_path / "prof")])
+    launch_train.main()
+    assert "done: 2 steps" in capsys.readouterr().out
+    assert obs_trace.span("x") is obs_trace._NOOP  # both sinks off again
+    lines = (tmp_path / "trace" / "trace.jsonl").read_text().splitlines()
+    names = {e["name"] for e in map(json.loads, lines) if e.get("ph") == "X"}
+    want = {"repro.data.batch", "repro.train.batch", "repro.train.step",
+            "repro.train.wait", "repro.train.checkpoint"}
+    assert want <= names
+    assert (tmp_path / "trace" / "metrics.json").exists()
+    pb = sorted((tmp_path / "prof").rglob("*.xplane.pb"))[-1]
+    host = {
+        ev.name
+        for plane in ProfileData.from_file(str(pb)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert want <= host
 
 
 def test_store_namespace_accounting_off_without_tracer():
