@@ -4,8 +4,9 @@ One process drives the main path once through its normal entry points, with
 weights, prompts and tokens made from ``--seed``:
 
   1. device   require a TPU (there is no CPU fallback); print kind and count
-  2. kernels  flash_attention, gmm and ssd_scan through kernels/ops.py at
-              qwen3-1.7b / MoE / mamba2-370m widths, each against kernels/ref.py
+  2. kernels  flash_attention, gmm, ssd_scan and wstream through
+              kernels/ops.py at qwen3-1.7b / MoE / mamba2-370m widths, each
+              against kernels/ref.py
   3. serve    qwen3-1.7b (all 28 layers) through make_decode_step, batch 4 and
               a bf16 cache of 2048: the prompt goes through decode steps, then
               16 greedy tokens; decode logits at the prompt positions are
@@ -62,6 +63,9 @@ GMM_TOL = 1e-1
 # ssd_scan is tested in f32 only; its bf16 bound is flash's, relative to the
 # output's scale (the reference returns f32, the kernel rounds to bf16)
 SSD_REL_TOL = 2e-2
+# wstream rounds the f32-accumulated product to bf16: half a bf16 ulp,
+# relative to the output's scale
+WSTREAM_REL_TOL = 2.0**-8
 # decode vs forward: both bf16 through 28 layers, in different attention
 # paths (cache of 2048 with kv_len masking vs the prompt alone). Bound on
 # max |decode - forward| relative to max |forward|.
@@ -175,6 +179,21 @@ def phase_kernels(seed: int):
     out.append(f"ssd_scan rel_err y={rel_y:.3g} state={rel_s:.3g} "
                f"tol={SSD_REL_TOL}")
     check(max(rel_y, rel_s) < SSD_REL_TOL, out[-1])
+
+    # weight streaming at qwen3-1.7b decode widths: the last layer of a
+    # stacked fp32 MLP weight, and a tied (V, D) table
+    x = jax.random.normal(ks[0], (4, 2048), bf16)
+    for name, shape, layer, tr in (("mlp", (28, 2048, 6144), 27, False),
+                                   ("table", (151936, 2048), 0, True)):
+        w = jax.random.normal(ks[1], shape, f32)
+        got = ops.wstream(x, w, layer, transpose=tr)
+        wl = (w if w.ndim == 2 else w[layer]).astype(bf16).astype(f32)
+        with highest:
+            want = x.astype(f32) @ (wl.T if tr else wl)
+        rel = max_err(got, want) / float(jnp.max(jnp.abs(want)))
+        out.append(f"wstream {name} rel_err={rel:.3g} tol={WSTREAM_REL_TOL:.3g}")
+        check(rel < WSTREAM_REL_TOL, out[-1])
+        del w
     return " | ".join(out)
 
 

@@ -10,12 +10,28 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.gmm import gmm as _gmm_pallas
 from repro.kernels.ibn_conv import ibn_pointwise as _ibn_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
+from repro.kernels.wstream import wstream_matmul as _wstream_pallas
 
 
 def on_tpu() -> bool:
     """Whether JAX's default device is a TPU. A backend that fails to start
     raises here: a broken chip is never taken for a CPU."""
     return jax.devices()[0].platform == "tpu"
+
+
+# A matmul over fp32 weights does 2*M*K*N flops per 4*K*N bytes: on a v5e
+# (197e12 flop/s over 819e9 B/s, 240 flop/B) it is bandwidth-bound while
+# M < 480, and there the weight-streaming kernel's one read of each weight
+# pays. A v5e reckoning: timed there at 4 to 479 rows, the kernel read the
+# stacked MLP weights 1.85x as fast as XLA's cast and matmul, and the table
+# as fast. Other TPU generations would move the cut-off.
+STREAM_ROWS = 480
+
+
+def streams(rows: int) -> bool:
+    """Whether a matmul of this many rows over stored fp32 weights reads them
+    through the weight-streaming kernel: on a TPU, below STREAM_ROWS."""
+    return rows < STREAM_ROWS and on_tpu()
 
 
 def flash_attention(
@@ -54,3 +70,13 @@ def ibn_pointwise(x, w, b, *, act: str = "relu", interpret: bool = False):
     if on_tpu() or interpret:
         return _ibn_pallas(x, w, b, act=act, interpret=interpret)
     return ref.ibn_pointwise_ref(x, w, b, act)
+
+
+def wstream(x, w, layer=0, *, transpose: bool = False, interpret: bool = False):
+    """x (M, K) @ w[layer].astype(x.dtype), the weight read in place in its
+    stored dtype: w is (L, K, N) or (K, N), or (L, N, K) or (N, K) with
+    transpose (a tied embedding table)."""
+    if on_tpu() or interpret:
+        return _wstream_pallas(x, w, layer, transpose=transpose,
+                               interpret=interpret)
+    return ref.wstream_ref(x, w, layer, transpose=transpose)

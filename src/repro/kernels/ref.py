@@ -67,3 +67,13 @@ def ibn_pointwise_ref(x, w, b, act: str = "relu") -> jax.Array:
     elif act == "silu":
         y = jax.nn.silu(y)
     return y.astype(x.dtype)
+
+
+def wstream_ref(x, w, layer=0, *, transpose: bool = False) -> jax.Array:
+    """x @ w[layer] with the weight rounded to x's dtype, f32 accumulation.
+    x: (M, K); w: (L, K, N) or (K, N), or (L, N, K) or (N, K) with
+    transpose -> (M, N) in x's dtype."""
+    w = (w if w.ndim == 2 else w[layer]).astype(x.dtype)
+    if transpose:
+        w = w.T
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32).astype(x.dtype)

@@ -10,13 +10,14 @@ Shape conventions:
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.common import dtype_of
 from repro.config import ModelConfig
+from repro.kernels import ops as kops
 from repro.obs import scopes
 
 # ---------------------------------------------------------------------------
@@ -265,8 +266,6 @@ def attention_core(q, k, v, cfg: ModelConfig, **kw) -> jax.Array:
         # and cache-masked calls (kv_len, traced q_offset) and non-TPU runs
         # take the numerically equivalent chunked path. A kernel that fails
         # to lower on the chip raises.
-        from repro.kernels import ops as kops
-
         no_cache = kw.get("kv_len") is None and isinstance(
             kw.get("q_offset", 0), int)
         if no_cache and kops.on_tpu():
@@ -277,6 +276,33 @@ def attention_core(q, k, v, cfg: ModelConfig, **kw) -> jax.Array:
         return chunked_attention(q, k, v, chunk=cfg.attn_chunk,
                                  unroll=cfg.unroll_scans, **kw)
     return naive_attention(q, k, v, **kw)
+
+
+# ---------------------------------------------------------------------------
+# Weight matmuls
+# ---------------------------------------------------------------------------
+
+
+class StreamedWeight(NamedTuple):
+    """A weight that a few-row matmul reads in place, in its stored dtype,
+    through the weight-streaming kernel: layer ``layer`` of a stacked
+    (L, K, N) parameter, or a whole 2-D matrix. The decode step hands these
+    to the layers in place of weight arrays."""
+
+    w: jax.Array
+    layer: Any = 0
+
+
+def linear(x: jax.Array, w, cdt, *, transpose: bool = False) -> jax.Array:
+    """x (..., K) @ w in the compute dtype cdt; with transpose w is stored
+    (N, K). A StreamedWeight goes through the kernel, with the same
+    rounding of the weight and f32 accumulation."""
+    if isinstance(w, StreamedWeight):
+        y = kops.wstream(x.reshape(-1, x.shape[-1]).astype(cdt), w.w, w.layer,
+                         transpose=transpose)
+        return y.reshape(x.shape[:-1] + y.shape[-1:])
+    w = w.astype(cdt)
+    return x @ (w.T if transpose else w)
 
 
 # ---------------------------------------------------------------------------
@@ -316,9 +342,9 @@ def attention_block(
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     cdt = dtype_of(cfg.compute_dtype)
     xc = x.astype(cdt)
-    q = (xc @ params["wq"].astype(cdt)).reshape(b, s, h, hd)
-    k = (xc @ params["wk"].astype(cdt)).reshape(b, s, kvh, hd)
-    v = (xc @ params["wv"].astype(cdt)).reshape(b, s, kvh, hd)
+    q = linear(xc, params["wq"], cdt).reshape(b, s, h, hd)
+    k = linear(xc, params["wk"], cdt).reshape(b, s, kvh, hd)
+    v = linear(xc, params["wv"], cdt).reshape(b, s, kvh, hd)
     if cfg.use_qk_norm:
         q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
@@ -349,7 +375,7 @@ def attention_block(
                 q_offset=cache_index,
                 kv_len=cache_index + s,
             )
-    out = out.reshape(b, s, h * hd) @ params["wo"].astype(cdt)
+    out = linear(out.reshape(b, s, h * hd), params["wo"], cdt)
     return out.astype(x.dtype), new_cache
 
 
@@ -372,10 +398,10 @@ def init_mlp(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
 def mlp_block(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     cdt = dtype_of(cfg.compute_dtype)
     xc = x.astype(cdt)
-    gate = xc @ params["wi_gate"].astype(cdt)
-    up = xc @ params["wi_up"].astype(cdt)
+    gate = linear(xc, params["wi_gate"], cdt)
+    up = linear(xc, params["wi_up"], cdt)
     act = jax.nn.silu(gate) if cfg.act == "silu" else jax.nn.gelu(gate)
-    return ((act * up) @ params["wo"].astype(cdt)).astype(x.dtype)
+    return linear(act * up, params["wo"], cdt).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +424,9 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Arr
     explicit shard_map over the model axis (table sharded on d_model): XLA's
     SPMD gather partitioning mis-compiles this pattern under jvp+scan
     (dynamic-slice size mismatch), and manual sharding is also faster — the
-    lookup is local per shard with zero collectives."""
+    lookup is local per shard with zero collectives. A StreamedWeight table
+    is gathered in its stored dtype and the rows cast: the same values,
+    without a cast copy of the whole table."""
     cdt = dtype_of(cfg.compute_dtype)
     table = params["embedding"]
     if pc is not None and pc.tp and table.shape[1] % pc.model_size == 0:
@@ -418,6 +446,8 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Arr
             out_specs=out_spec,
             check_vma=False,
         )(tokens, table)
+    elif isinstance(table, StreamedWeight):
+        x = table.w[tokens].astype(cdt)
     else:
         x = table.astype(cdt)[tokens]
     if cfg.embed_scale:
@@ -428,10 +458,10 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, pc=None) -> jax.Arr
 def unembed(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     cdt = dtype_of(cfg.compute_dtype)
     if cfg.tie_embeddings:
-        w = params["embedding"].astype(cdt).T
+        logits = linear(x.astype(cdt), params["embedding"], cdt, transpose=True)
     else:
-        w = params["unembed"].astype(cdt)
-    logits = (x.astype(cdt) @ w).astype(dtype_of(cfg.logits_dtype))
+        logits = linear(x.astype(cdt), params["unembed"], cdt)
+    logits = logits.astype(dtype_of(cfg.logits_dtype))
     if cfg.logit_softcap > 0:
         c = cfg.logit_softcap
         logits = jnp.tanh(logits / c) * c

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.common import dtype_of, fold_rng
 from repro.config import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.obs import scopes
 from repro.parallel.ctx import constrain
@@ -180,21 +181,42 @@ def decode_step(
     cfg: ModelConfig,
     pc=None,
 ) -> tuple[jax.Array, dict]:
-    """One decode step. tokens: (B, 1). cache: stacked (L, ...) kv cache.
-    Returns (logits (B, 1, V), new_cache)."""
+    """One decode step. tokens: (B, S). cache: stacked (L, ...) kv cache.
+    Returns (logits (B, S, V), new_cache).
+
+    A one-device step whose B*S rows kernels.ops.streams admits reads every
+    weight matrix in place through the weight-streaming kernel: the layer
+    scan closes over the stacked weights and scans the layer index beside
+    the cache, and the head reads the table as stored. Other steps cast the
+    weights for XLA's matmuls."""
+    b, s = tokens.shape
+    stream = pc is None and kops.streams(b * s)
+    if stream:
+        params = {**params, "embed": {k: L.StreamedWeight(w)
+                                      for k, w in params["embed"].items()}}
+        stacked = params["layers"]
+        layers_xs = jnp.arange(cfg.num_layers)
+
+        def layer_params(l):  # matrices stay stacked; norm scales are sliced
+            return jax.tree.map(
+                lambda w: L.StreamedWeight(w, l) if w.ndim == 3 else w[l],
+                stacked)
+    else:
+        layers_xs = params["layers"]
+        layer_params = lambda p: p
+
     x = L.embed(params["embed"], tokens, cfg, pc)
     x = constrain(x, pc, None, None,
                   pc.act_model_axis if pc and x.shape[-1] % pc.model_size == 0
                   else None, batch_dim=0)
-    b, s, _ = x.shape
     positions = jnp.broadcast_to(
         cache_index + jnp.arange(s, dtype=jnp.int32), (b, s)
     ).astype(jnp.int32)
 
     def body(x, scanned):
-        layer_params, layer_cache = scanned
+        layer, layer_cache = scanned
         y, new_layer_cache = block_apply(
-            layer_params,
+            layer_params(layer),
             x,
             cfg,
             positions=positions,
@@ -204,7 +226,7 @@ def decode_step(
         y = constrain(y, pc, None, None, None, batch_dim=0)
         return y, new_layer_cache
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
+    x, new_cache = jax.lax.scan(body, x, (layers_xs, cache),
                                 unroll=cfg.num_layers if cfg.unroll_scans else 1)
     logits = L.lm_head(params, x, cfg)
     logits = constrain(logits, pc, None, None, pc.act_model_axis if pc else None,

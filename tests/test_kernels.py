@@ -1,5 +1,8 @@
 """Pallas kernels swept over shapes/dtypes vs the pure-jnp oracles
 (interpret=True executes the kernel body on CPU)."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -9,6 +12,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gmm import gmm
 from repro.kernels.ibn_conv import ibn_pointwise
 from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels.wstream import wstream_matmul
 
 RNG = jax.random.PRNGKey(0)
 
@@ -96,6 +100,107 @@ def test_ibn_pointwise(n, ci, co, act):
     y = ibn_pointwise(x, w, b, act=act, block_n=64, block_f=32, block_k=32,
                       interpret=True)
     assert jnp.max(jnp.abs(y - ref.ibn_pointwise_ref(x, w, b, act))) < 1e-4
+
+
+@pytest.mark.parametrize("m,w_shape,layer,transpose,block_k,block_n", [
+    (1, (3, 256, 384), 2, False, 128, 128),  # two K tiles, last layer
+    (4, (2, 256, 256), 1, False, None, None),  # default blocks
+    (8, (256, 300), 0, False, 128, 128),  # one (K, N) matrix, ragged N
+    (4, (1000, 256), 0, True, 128, 256),  # tied (V, D) table, ragged V
+    (37, (2, 256, 384), 1, False, 128, 128),  # rows off the sublane tiling
+])
+def test_wstream(m, w_shape, layer, transpose, block_k, block_n):
+    """x @ w[layer].astype(bf16) from the fp32 weight read in place: within
+    bf16's rounding of the f32-accumulated product."""
+    ks = jax.random.split(RNG, 2)
+    k = w_shape[-1] if transpose else w_shape[-2]
+    x = jax.random.normal(ks[0], (m, k), jnp.bfloat16)
+    w = jax.random.normal(ks[1], w_shape, jnp.float32)
+    y = wstream_matmul(x, w, layer, transpose=transpose, block_k=block_k,
+                       block_n=block_n, interpret=True)
+    wl = (w if w.ndim == 2 else w[layer]).astype(jnp.bfloat16)
+    want = jnp.matmul(x, wl.T if transpose else wl,
+                      preferred_element_type=jnp.float32)
+    assert y.dtype == jnp.bfloat16 and y.shape == want.shape
+    err = jnp.abs(y.astype(jnp.float32) - want)
+    assert bool(jnp.all(err <= 2.0**-8 * jnp.abs(want) + 1e-5)), float(err.max())
+
+
+def _smoke_decode(monkeypatch, stream: bool, steps: int = 3):
+    """Greedy one-token decode steps at SMOKE size after a 4-token prompt,
+    on the weight-streaming path (TPU dispatch patched in, the kernel in
+    interpret mode) or on XLA's. Returns each step's tokens and logits, and
+    the weight shape of each kernel call traced."""
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import api, transformer
+
+    cfg = dataclasses.replace(configs.smoke("qwen3_1_7b"), tie_embeddings=True)
+    params = api.init(RNG, cfg)
+    calls = []
+
+    def counted(*a, **k):
+        calls.append(a[1].shape)
+        return wstream_matmul(*a, **k, interpret=True)
+
+    monkeypatch.setattr(ops, "wstream", counted)
+    monkeypatch.setattr(ops, "on_tpu", lambda: stream)
+    step = jax.jit(functools.partial(transformer.decode_step, cfg=cfg))
+    cache = transformer.init_cache(cfg, 2, 16)
+    prompt = jax.random.randint(RNG, (2, 4), 0, cfg.vocab_size, jnp.int32)
+    tok, out = prompt, []
+    for t in range(steps):
+        index = jnp.int32(0 if t == 0 else 3 + t)
+        logits, cache = step(params, cache, tok, index)
+        tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
+        out.append((tok, logits[:, -1]))
+    return out, calls
+
+
+def test_decode_step_streams_weights_through_the_kernel(monkeypatch):
+    """On a TPU a few-row decode step reads each weight matrix through the
+    kernel (7 per layer body, 1 for the tied head) with the tokens of XLA's
+    path and logits within one bf16 ulp of the largest."""
+    from repro import configs
+
+    want, none = _smoke_decode(monkeypatch, stream=False)
+    got, calls = _smoke_decode(monkeypatch, stream=True)
+    assert none == []
+    c = configs.smoke("qwen3_1_7b")
+    n, d, q, kv, f = c.num_layers, c.d_model, c.num_heads * c.head_dim, \
+        c.num_kv_heads * c.head_dim, c.d_ff
+    per_trace = [(n, d, q), (n, d, kv), (n, d, kv), (n, q, d),  # q k v o
+                 (n, d, f), (n, d, f), (n, f, d),  # gate up down
+                 (c.vocab_size, d)]  # the tied head
+    # traced twice: the prompt's 8 rows, then the one-token steps' 2
+    assert calls == per_trace * 2
+    for (tok_w, lg_w), (tok_g, lg_g) in zip(want, got):
+        assert jnp.array_equal(tok_w, tok_g)
+        top = float(jnp.max(jnp.abs(lg_w)))
+        ulp = 2.0 ** (jnp.floor(jnp.log2(top)) - 7)
+        assert float(jnp.max(jnp.abs(lg_w - lg_g))) <= ulp
+
+
+def test_wide_calls_and_training_keep_xla_matmuls(monkeypatch):
+    """A decode call of 1,024 rows and a training forward never take the
+    kernel, even on a TPU."""
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import api, transformer
+
+    def kernel(*a, **k):
+        raise AssertionError("call reached the weight-streaming kernel")
+
+    monkeypatch.setattr(ops, "wstream", kernel)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(configs.smoke("qwen3_1_7b"), tie_embeddings=True)
+    params = api.init(RNG, cfg)
+    tokens = jnp.zeros((4, 256), jnp.int32)
+    cache = transformer.init_cache(cfg, 4, 256)
+    jax.eval_shape(functools.partial(transformer.decode_step, cfg=cfg),
+                   params, cache, tokens, jnp.int32(0))
+    jax.eval_shape(lambda p, b: api.loss_fn(p, b, cfg)[0], params,
+                   {"tokens": tokens[:1, :8], "labels": tokens[:1, :8]})
 
 
 def test_flash_pallas_routes_cache_calls_to_chunked(monkeypatch):
